@@ -63,6 +63,7 @@ class TranscriptSnapshot:
     temperature: float
     seed: int                     # sampling seed that keys this stream
     arrival_s: float = 0.0
+    admit_s: Optional[float] = None          # first admission is preserved
     prefill_done_s: Optional[float] = None   # original TTFT is preserved
     attempts: int = 0
     deadline_s: Optional[float] = None
@@ -77,6 +78,7 @@ class TranscriptSnapshot:
                    temperature=float(req.temperature),
                    seed=int(seed),
                    arrival_s=float(req.arrival_s),
+                   admit_s=req.admit_s,
                    prefill_done_s=req.prefill_done_s,
                    attempts=int(req.attempts),
                    deadline_s=req.deadline_s)
@@ -89,6 +91,7 @@ class TranscriptSnapshot:
                 "temperature": float(self.temperature),
                 "seed": int(self.seed),
                 "arrival_s": float(self.arrival_s),
+                "admit_s": self.admit_s,
                 "prefill_done_s": self.prefill_done_s,
                 "attempts": int(self.attempts),
                 "deadline_s": self.deadline_s}
@@ -102,6 +105,7 @@ class TranscriptSnapshot:
                    temperature=float(d["temperature"]),
                    seed=int(d["seed"]),
                    arrival_s=float(d.get("arrival_s", 0.0)),
+                   admit_s=d.get("admit_s"),
                    prefill_done_s=d.get("prefill_done_s"),
                    attempts=int(d.get("attempts", 0)),
                    deadline_s=d.get("deadline_s"))

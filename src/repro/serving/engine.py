@@ -145,6 +145,16 @@ correctness anchor (tests/test_faults.py), and it is also what makes
 cross-site failover accounting honest: recovered tokens are real tokens
 the user would have received anyway, never a divergent re-generation.
 
+Spans and stamps: every step writes host spans through
+``jax.profiler.TraceAnnotation`` (``engine.step`` and, nested in it,
+``engine.admit``, ``engine.prefill``, ``engine.extend``,
+``engine.first_token``, ``engine.decode``, ``engine.sample``,
+``engine.retire``), with the row counts each dispatch serves and computes
+as arguments. They record only while a profiler session runs, on the
+profiler's clock beside the device's ops. ``Request.admit_s`` stamps when a
+request left the queue for a slot, on the engine clock, so a TTFT splits
+into the wait before admission and the service after it.
+
 Backpressure and brownout: ``queue_watermark`` rejects new submissions
 beyond a queue depth (fail fast under overload); ``set_brownout(frac)``
 enters power-brownout mode — admissions shed their ``max_new_tokens``
@@ -203,6 +213,7 @@ class Request:
     resumed_from: int = 0               # tokens carried in from a snapshot
     # filled by the engine
     tokens: list = field(default_factory=list)
+    admit_s: Optional[float] = None     # first left the queue for a slot
     prefill_done_s: Optional[float] = None
     finish_s: Optional[float] = None
 
@@ -223,6 +234,12 @@ class Request:
         if self.finish_s is None or len(self.tokens) < 2:
             return None
         return (self.finish_s - self.prefill_done_s) / max(len(self.tokens) - 1, 1)
+
+
+def _host_span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """Host span ``engine.<name>`` with integer arguments; it costs about a
+    microsecond and records nothing unless a profiler session runs."""
+    return jax.profiler.TraceAnnotation("engine." + name, **args)
 
 
 def _insert_leaf(engine_leaf, req_leaf, slot: int):
@@ -447,8 +464,10 @@ class ServingEngine:
         # steps (slot -> [req, full_prompt, consumed]); the slot's pages /
         # cache row are already reserved while the arbiter fills them
         self._pend: dict[int, list] = {}
-        self._decode = jax.jit(model.decode_fn)
-        self._prefill = jax.jit(model.prefill_fn)
+        # named methods, not the model's functools.partials: XLA names a
+        # program after its function, and a partial has no name
+        self._decode = jax.jit(self._decode_call)
+        self._prefill = jax.jit(self._prefill_call)
         self._extend = jax.jit(self._masked_extend)
         self._extend_paged = jax.jit(self._masked_extend_paged)
         self._decode_masked = jax.jit(self._masked_decode)
@@ -489,6 +508,12 @@ class ServingEngine:
             return np.asarray(req.prompt, np.int32)
         return np.concatenate([np.asarray(req.prompt, np.int32),
                                np.asarray(req.tokens, np.int32)])
+
+    def _prefill_call(self, params, inputs):
+        return self.model.prefill_fn(params, inputs)
+
+    def _decode_call(self, params, inputs, cache):
+        return self.model.decode_fn(params, inputs, cache)
 
     def _masked_extend(self, params, tokens, mask, cache):
         """One extend chunk over the full engine cache; rows with
@@ -643,43 +668,45 @@ class ServingEngine:
         """
         if not items:
             return
-        rows = jnp.asarray([row for _, _, row in items], jnp.int32)
-        slot_arr = jnp.asarray([slot for slot, _, _ in items], jnp.int32)
-        idxs = jnp.asarray([len(req.tokens) for _, req, _ in items],
-                           jnp.int32)
-        temps = jnp.asarray([req.temperature for _, req, _ in items],
-                            jnp.float32)
-        # slot base keys were pinned at admission (engine seed or the
-        # request's carried seed); the token index is len(tokens) — 0 for
-        # a fresh request, the resume point for a replayed transcript —
-        # so a resumed stream continues exactly where it left off
-        keys = fold_idx(self._slot_keys[slot_arr], idxs)
-        toks = np.asarray(sample_batch(logits[rows], keys, temps))
-        now = self._clock()
-        live_slots, live_toks = [], []
-        for j, (slot, req, _) in enumerate(items):
-            tok = int(toks[j])
-            req.tokens.append(tok)
-            if req.prefill_done_s is None:
-                # a resumed request keeps its ORIGINAL prefill time: TTFT
-                # measures when the user first saw a token, not the replay
-                req.prefill_done_s = now
-            self.metrics.prefills += 1
-            if len(req.tokens) >= req.max_new_tokens or tok == self.eos:
-                # complete at admission: the prompt's last logits already
-                # gave the only remaining requested (or an EOS) token —
-                # the slot never goes live, no unrequested decode runs
-                req.finish_s = now
-                self.metrics.completed.append(req)
-                self.release_slot(slot)
-                continue
-            self.active[slot] = req
-            self.new_counts[slot] = len(req.tokens)
-            live_slots.append(slot)
-            live_toks.append(tok)
-        if live_slots:
-            self.last_token = self.last_token.at[jnp.asarray(live_slots)].set(
-                jnp.asarray(live_toks, jnp.int32))
+        with _host_span("first_token", rows=len(items)):
+            rows = jnp.asarray([row for _, _, row in items], jnp.int32)
+            slot_arr = jnp.asarray([slot for slot, _, _ in items], jnp.int32)
+            idxs = jnp.asarray([len(req.tokens) for _, req, _ in items],
+                               jnp.int32)
+            temps = jnp.asarray([req.temperature for _, req, _ in items],
+                                jnp.float32)
+            # slot base keys were pinned at admission (engine seed or the
+            # request's carried seed); the token index is len(tokens) — 0 for
+            # a fresh request, the resume point for a replayed transcript —
+            # so a resumed stream continues exactly where it left off
+            keys = fold_idx(self._slot_keys[slot_arr], idxs)
+            toks = np.asarray(sample_batch(logits[rows], keys, temps))
+            now = self._clock()
+            live_slots, live_toks = [], []
+            for j, (slot, req, _) in enumerate(items):
+                tok = int(toks[j])
+                req.tokens.append(tok)
+                if req.prefill_done_s is None:
+                    # a resumed request keeps its ORIGINAL prefill time: TTFT
+                    # measures when the user first saw a token, not the replay
+                    req.prefill_done_s = now
+                self.metrics.prefills += 1
+                if len(req.tokens) >= req.max_new_tokens or tok == self.eos:
+                    # complete at admission: the prompt's last logits already
+                    # gave the only remaining requested (or an EOS) token —
+                    # the slot never goes live, no unrequested decode runs
+                    req.finish_s = now
+                    self.metrics.completed.append(req)
+                    self.release_slot(slot)
+                    continue
+                self.active[slot] = req
+                self.new_counts[slot] = len(req.tokens)
+                live_slots.append(slot)
+                live_toks.append(tok)
+            if live_slots:
+                at = jnp.asarray(live_slots)
+                self.last_token = self.last_token.at[at].set(
+                    jnp.asarray(live_toks, jnp.int32))
 
     def _sweep_waiting_deadlines(self, now: float) -> None:
         """Expire queued requests whose absolute deadline has passed —
@@ -695,15 +722,18 @@ class ServingEngine:
                 keep.append(req)
         self.waiting = keep
 
-    def _admit(self) -> None:
+    def _admit(self) -> tuple[int, int]:
+        """Admit what the budget allows from the queue; returns (requests
+        admitted, prompt tokens scheduled)."""
         if not self.waiting:
-            return
+            return 0, 0
         now = self._clock()
         if self._has_deadlines:
             self._sweep_waiting_deadlines(now)
         free = [i for i, r in enumerate(self.active)
                 if r is None and i not in self._pend]
         admits: list[tuple[int, Request]] = []
+        stamped: list[Request] = []    # first admitted in this round
         held: list[Request] = []       # backoff-gated, keep queue order
         spent = 0
         budget = self.admit_token_budget
@@ -751,7 +781,7 @@ class ServingEngine:
                 break
             if (admits and budget is not None and spent + S > budget):
                 break  # budget spent; the rest waits for the next step
-            self.waiting.popleft()
+            self._leave_queue(stamped)
             if self.brownout < 1.0 and not req.tokens:
                 # graceful degradation: fresh admissions under brownout
                 # shed max_new_tokens instead of being dropped (resumed
@@ -772,7 +802,7 @@ class ServingEngine:
         if held:
             self.waiting.extendleft(reversed(held))
         if not admits:
-            return
+            return 0, 0
         for slot, req in admits:
             self._slot_keys = self._slot_keys.at[slot].set(
                 self._request_base_key(req))
@@ -793,44 +823,58 @@ class ServingEngine:
             # ndarray prompts and raise. A resumed request keeps its
             # carried transcript prefix — only tokens sampled during the
             # failed round are rolled back.
-            self._rollback_admits(admits)
+            self._rollback_admits(admits, stamped)
             raise
+        return len(admits), spent
+
+    def _leave_queue(self, stamped: list) -> None:
+        """Pop the queue's head for a slot, stamping its first admission;
+        ``stamped`` collects the requests this round stamped, which a
+        failed round unstamps."""
+        req = self.waiting.popleft()
+        if req.admit_s is None:
+            req.admit_s = self._clock()
+            stamped.append(req)
 
     def _admit_serial(self, slot: int, req: Request) -> None:
         """Reference path: pow2-prefix prefill + serial B=1 decode tail."""
         full = self._effective_prompt(req)
         S = len(full)
         bucket = 1 << (S.bit_length() - 1)
-        logits, req_cache = self._prefill(
-            self.params, self._prefill_inputs(full[None, :bucket]))
-        self.metrics.prefill_calls += 1
-        if bucket < S:
-            # continue the prompt token-by-token at B=1: decode(prefill
-            # of a prefix) is the exact sequential continuation, so the
-            # final logits/cache match a full-length prefill
-            if self._b1_cache is None:
-                from repro.models import transformer as T
-                self._b1_cache = T.make_decode_cache(self.cfg, 1, self.max_seq)
-            req_cache = insert_cache(self._b1_cache, req_cache, 0)
-            for tok in full[bucket:]:
-                logits, req_cache = self._decode(
-                    self.params, {"token": jnp.asarray([tok], jnp.int32)},
-                    req_cache)
-                self.metrics.prefill_calls += 1
-        try:
-            if self.paged:
-                self._insert_group_cache(req_cache,
-                                         np.asarray([slot], np.int32))
-                # logical length = the request cache's pos (covers the VLM
-                # patch prefix); NOT the kv length — the serial tail cache
-                # is padded out to max_seq
-                self._slot_len[slot] = int(req_cache["pos"][0])
-            else:
-                self.cache = insert_cache(self.cache, req_cache, slot)
-            self._finalize_admits([(slot, req, 0)], logits)
-        except Exception:
-            self._reject_failed(slot, req)
-            raise
+        # one span over the B=1 prefill, its decode tail and the insert;
+        # the first token nests in it
+        with _host_span("prefill", bucket=bucket, rows=1, batch=1):
+            logits, req_cache = self._prefill(
+                self.params, self._prefill_inputs(full[None, :bucket]))
+            self.metrics.prefill_calls += 1
+            if bucket < S:
+                # continue the prompt token-by-token at B=1: decode(prefill
+                # of a prefix) is the exact sequential continuation, so the
+                # final logits/cache match a full-length prefill
+                if self._b1_cache is None:
+                    from repro.models import transformer as T
+                    self._b1_cache = T.make_decode_cache(self.cfg, 1,
+                                                         self.max_seq)
+                req_cache = insert_cache(self._b1_cache, req_cache, 0)
+                for tok in full[bucket:]:
+                    logits, req_cache = self._decode(
+                        self.params, {"token": jnp.asarray([tok], jnp.int32)},
+                        req_cache)
+                    self.metrics.prefill_calls += 1
+            try:
+                if self.paged:
+                    self._insert_group_cache(req_cache,
+                                             np.asarray([slot], np.int32))
+                    # logical length = the request cache's pos (covers the
+                    # VLM patch prefix); NOT the kv length — the serial tail
+                    # cache is padded out to max_seq
+                    self._slot_len[slot] = int(req_cache["pos"][0])
+                else:
+                    self.cache = insert_cache(self.cache, req_cache, slot)
+                self._finalize_admits([(slot, req, 0)], logits)
+            except Exception:
+                self._reject_failed(slot, req)
+                raise
 
     def _reject_failed(self, slot: int, req: Request) -> None:
         """Admission error path: release the slot and record the failing
@@ -850,17 +894,19 @@ class ServingEngine:
         """One masked extend-chunk dispatch over the full engine batch
         (dense: cache-select mask; paged: sentinel table rows, full table
         width). Shared by the batched tail loop and the async arbiter."""
-        if self.paged:
-            logits, new_cache = self._extend_paged(
-                self.params, jnp.asarray(toks), jnp.asarray(mask),
-                self._call_cache(self._maxP))
-            self.cache = self._strip_table(new_cache)
-            for slot in takers:
-                self._slot_len[slot] += C
-        else:
-            logits, self.cache = self._extend(
-                self.params, jnp.asarray(toks), jnp.asarray(mask),
-                self.cache)
+        with _host_span("extend", chunk=C, rows=len(takers),
+                        batch=len(toks)):
+            if self.paged:
+                logits, new_cache = self._extend_paged(
+                    self.params, jnp.asarray(toks), jnp.asarray(mask),
+                    self._call_cache(self._maxP))
+                self.cache = self._strip_table(new_cache)
+                for slot in takers:
+                    self._slot_len[slot] += C
+            else:
+                logits, self.cache = self._extend(
+                    self.params, jnp.asarray(toks), jnp.asarray(mask),
+                    self.cache)
         self.metrics.prefill_calls += 1
         self.metrics.extend_chunks += 1
         return logits
@@ -884,16 +930,19 @@ class ServingEngine:
             for r, (slot, req, full) in enumerate(group):
                 toks[r] = full[:bucket]
                 slots[r] = slot
-            logits, gcache = self._prefill(self.params,
-                                           self._prefill_inputs(toks))
-            self.metrics.prefill_calls += 1
-            self._insert_group_cache(gcache, slots)
-            if self.paged:
-                # every row of the bucket group lands at the same logical
-                # length: the group cache's pos (covers the VLM prefix)
-                S_ins = int(gcache["pos"][0])
-                for slot, _req, _full in group:
-                    self._slot_len[slot] = S_ins
+            with _host_span("prefill", bucket=bucket, rows=len(group),
+                            batch=kp):
+                logits, gcache = self._prefill(self.params,
+                                               self._prefill_inputs(toks))
+                self.metrics.prefill_calls += 1
+                self._insert_group_cache(gcache, slots)
+                if self.paged:
+                    # every row of the bucket group lands at the same
+                    # logical length: the group cache's pos (covers the
+                    # VLM prefix)
+                    S_ins = int(gcache["pos"][0])
+                    for slot, _req, _full in group:
+                        self._slot_len[slot] = S_ins
             fins = []
             for r, (slot, req, full) in enumerate(group):
                 if bucket == len(full):
@@ -964,12 +1013,12 @@ class ServingEngine:
             spent += C * len(takers)
         return spent
 
-    def _admit_async(self) -> int:
+    def _admit_async(self) -> tuple[int, int]:
         """One bounded slice of admission work: a guaranteed arbiter chunk
         for in-flight tails, new-request bucket prefills with the
         remaining budget (tails deferred to ``self._pend``), then leftover
-        budget on more tail chunks. Returns prompt tokens spent (the
-        step's admission-stall accounting)."""
+        budget on more tail chunks. Returns (requests admitted, prompt
+        tokens spent)."""
         now = self._clock()
         if self._has_deadlines:
             self._sweep_waiting_deadlines(now)
@@ -980,6 +1029,7 @@ class ServingEngine:
         free = [i for i, r in enumerate(self.active)
                 if r is None and i not in self._pend]
         admits: list[tuple[int, Request]] = []
+        stamped: list[Request] = []
         held: list[Request] = []
         prefix = (self.cfg.num_prefix_embeddings
                   if self.cfg.family == "vlm" else 0)
@@ -1012,7 +1062,7 @@ class ServingEngine:
             bucket = 1 << (S.bit_length() - 1)
             if (admits or spent) and spent + bucket > budget:
                 break
-            self.waiting.popleft()
+            self._leave_queue(stamped)
             if self.brownout < 1.0 and not req.tokens:
                 want = req.max_new_tokens
                 shed_to = max(1, int(math.ceil(want * self.brownout)))
@@ -1033,10 +1083,10 @@ class ServingEngine:
             try:
                 self._prefill_async(admits)
             except Exception:
-                self._rollback_admits(admits)
+                self._rollback_admits(admits, stamped)
                 raise
         spent += self._arbiter(budget - spent)
-        return spent
+        return len(admits), spent
 
     def _prefill_async(self, admits: list) -> None:
         """Bucket-group prefills for a fresh async admission wave;
@@ -1055,14 +1105,16 @@ class ServingEngine:
             for r, (slot, _req, full) in enumerate(group):
                 toks[r] = full[:bucket]
                 slots[r] = slot
-            logits, gcache = self._prefill(self.params,
-                                           self._prefill_inputs(toks))
-            self.metrics.prefill_calls += 1
-            self._insert_group_cache(gcache, slots)
-            if self.paged:
-                S_ins = int(gcache["pos"][0])
-                for slot, _req, _full in group:
-                    self._slot_len[slot] = S_ins
+            with _host_span("prefill", bucket=bucket, rows=len(group),
+                            batch=kp):
+                logits, gcache = self._prefill(self.params,
+                                               self._prefill_inputs(toks))
+                self.metrics.prefill_calls += 1
+                self._insert_group_cache(gcache, slots)
+                if self.paged:
+                    S_ins = int(gcache["pos"][0])
+                    for slot, _req, _full in group:
+                        self._slot_len[slot] = S_ins
             fins = []
             for r, (slot, req, full) in enumerate(group):
                 if bucket == len(full):
@@ -1071,10 +1123,12 @@ class ServingEngine:
                     self._pend[slot] = [req, full, bucket]
             self._finalize_admits(fins, logits)
 
-    def _rollback_admits(self, admits: list) -> None:
+    def _rollback_admits(self, admits: list, stamped: list) -> None:
         """Failed-round cleanup shared with ``_admit``: anything not yet
         settled (live, pending, completed or rejected) goes back to the
-        front of the queue with clean state and its slot/pages released."""
+        front of the queue with clean state and its slot/pages released.
+        A request this round stamped (``stamped``) loses the stamp; one
+        admitted before keeps it."""
         requeue = []
         for slot, req in admits:
             settled = (self.active[slot] is req
@@ -1084,6 +1138,8 @@ class ServingEngine:
                        or any(r is req for r in self.metrics.rejected))
             if not settled:
                 del req.tokens[req.resumed_from:]
+                if any(r is req for r in stamped):
+                    req.admit_s = None
                 self.release_slot(slot)
                 requeue.append(req)
         self.waiting.extendleft(reversed(requeue))
@@ -1155,10 +1211,10 @@ class ServingEngine:
     def resume(self, snap: TranscriptSnapshot, *,
                not_before_s: float = 0.0) -> Optional[Request]:
         """Re-admit a preempted transcript. The carried seed keeps the
-        stream's keys; the carried ``prefill_done_s`` keeps the original
-        TTFT honest. Returns the queued Request, or None when the
-        watermark rejected it (the caller keeps the snapshot and may retry
-        elsewhere)."""
+        stream's keys; the carried ``admit_s`` and ``prefill_done_s`` keep
+        the original admission and TTFT honest. Returns the queued Request,
+        or None when the watermark rejected it (the caller keeps the
+        snapshot and may retry elsewhere)."""
         req = Request(rid=snap.rid,
                       prompt=np.asarray(snap.prompt, np.int32),
                       max_new_tokens=snap.max_new_tokens,
@@ -1170,6 +1226,7 @@ class ServingEngine:
                       attempts=snap.attempts,
                       resumed_from=len(snap.tokens),
                       tokens=list(snap.tokens),
+                      admit_s=snap.admit_s,
                       prefill_done_s=snap.prefill_done_s)
         if not self.submit(req):
             return None
@@ -1213,7 +1270,10 @@ class ServingEngine:
     # --------------------------------------------------------------- step
     def step(self) -> int:
         """Admit waiting requests, run one batched decode. Returns #active."""
-        with jax.default_device(self.device):
+        live = sum(r is not None for r in self.active)
+        with _host_span("step", live=live, waiting=len(self.waiting),
+                        pending=len(self._pend)), \
+                jax.default_device(self.device):
             return self._step()
 
     def _step(self) -> int:
@@ -1234,10 +1294,12 @@ class ServingEngine:
                     self.metrics.lost_tokens += len(r.tokens)
                     self.release_slot(i)   # pops the pend entry too
         pc_before = self.metrics.prefill_calls
-        if self.admit_mode == "async":
-            self._admit_async()
-        else:
-            self._admit()
+        admit = _host_span("admit")
+        with admit:
+            admitted, tokens = (self._admit_async()
+                                if self.admit_mode == "async"
+                                else self._admit())
+            admit.set_metadata(admitted=admitted, tokens=tokens)
         live = [i for i, r in enumerate(self.active) if r is not None]
         if not live:
             if self.metrics.prefill_calls > pc_before:
@@ -1245,58 +1307,67 @@ class ServingEngine:
                 # the async overlap exists to shrink
                 self.metrics.admit_stall_steps += 1
             return 0
-        if self.admit_mode == "async":
-            # pending-admission rows are masked out of the decode (their
-            # half-filled caches must not move)
-            mask = np.zeros((self.max_batch,), bool)
-            mask[live] = True
-            if self.paged:
-                logits, new_cache = self._decode_masked(
+        width = self._decode_width(live) if self.paged else 0
+        with _host_span("decode", rows=len(live), batch=self.max_batch,
+                        pages=width):
+            if self.admit_mode == "async":
+                # pending-admission rows are masked out of the decode
+                # (their half-filled caches must not move)
+                mask = np.zeros((self.max_batch,), bool)
+                mask[live] = True
+                if self.paged:
+                    logits, new_cache = self._decode_masked(
+                        self.params, {"token": self.last_token},
+                        jnp.asarray(mask), self._call_cache(width))
+                    self.cache = self._strip_table(new_cache)
+                else:
+                    logits, self.cache = self._decode_masked(
+                        self.params, {"token": self.last_token},
+                        jnp.asarray(mask), self.cache)
+            elif self.paged:
+                logits, new_cache = self._decode(
                     self.params, {"token": self.last_token},
-                    jnp.asarray(mask),
-                    self._call_cache(self._decode_width(live)))
+                    self._call_cache(width))
                 self.cache = self._strip_table(new_cache)
             else:
-                logits, self.cache = self._decode_masked(
-                    self.params, {"token": self.last_token},
-                    jnp.asarray(mask), self.cache)
-        elif self.paged:
-            logits, new_cache = self._decode(
-                self.params, {"token": self.last_token},
-                self._call_cache(self._decode_width(live)))
-            self.cache = self._strip_table(new_cache)
-        else:
-            logits, self.cache = self._decode(
-                self.params, {"token": self.last_token}, self.cache)
+                logits, self.cache = self._decode(
+                    self.params, {"token": self.last_token}, self.cache)
         self.metrics.decode_steps += 1
         if self.paged:
             for i in live:
                 self._slot_len[i] += 1
-        temps = np.zeros(self.max_batch, np.float32)
-        idxs = np.zeros(self.max_batch, np.int32)
-        for i in live:
-            temps[i] = self.active[i].temperature
-            idxs[i] = len(self.active[i].tokens)
-        # per-(request, token-index) keys + per-row temperatures: a row's
-        # draw is independent of its batch-mates and its admission order.
-        # Slot base keys were pinned at admission (fold_idx on top equals
-        # fold_keys bitwise), so a resumed request keeps its origin stream
-        keys = fold_idx(self._slot_keys, jnp.asarray(idxs))
-        toks = sample_batch(logits, keys, jnp.asarray(temps))
-        toks_np = np.asarray(toks)
+        with _host_span("sample"):
+            temps = np.zeros(self.max_batch, np.float32)
+            idxs = np.zeros(self.max_batch, np.int32)
+            for i in live:
+                temps[i] = self.active[i].temperature
+                idxs[i] = len(self.active[i].tokens)
+            # per-(request, token-index) keys + per-row temperatures: a
+            # row's draw is independent of its batch-mates and its
+            # admission order. Slot base keys were pinned at admission
+            # (fold_idx on top equals fold_keys bitwise), so a resumed
+            # request keeps its origin stream
+            keys = fold_idx(self._slot_keys, jnp.asarray(idxs))
+            toks = sample_batch(logits, keys, jnp.asarray(temps))
+            toks_np = np.asarray(toks)
         self.last_token = toks
         self.metrics.steps += 1
-        now = self._clock()
-        for i in live:
-            req = self.active[i]
-            req.tokens.append(int(toks_np[i]))
-            self.new_counts[i] += 1
-            done = (self.new_counts[i] >= req.max_new_tokens
-                    or int(toks_np[i]) == self.eos)
-            if done:
-                req.finish_s = now
-                self.metrics.completed.append(req)
-                self.release_slot(i)
+        retire = _host_span("retire")
+        with retire:
+            now = self._clock()
+            finished = 0
+            for i in live:
+                req = self.active[i]
+                req.tokens.append(int(toks_np[i]))
+                self.new_counts[i] += 1
+                done = (self.new_counts[i] >= req.max_new_tokens
+                        or int(toks_np[i]) == self.eos)
+                if done:
+                    req.finish_s = now
+                    self.metrics.completed.append(req)
+                    self.release_slot(i)
+                    finished += 1
+            retire.set_metadata(finished=finished)
         return len([r for r in self.active if r is not None])
 
     def run(self, max_steps: int = 10_000) -> EngineMetrics:
