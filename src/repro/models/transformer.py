@@ -440,8 +440,9 @@ def extend_fn(cfg: ModelConfig, parallel: Optional[ParallelConfig], params,
 
     This is what lets the serving engine admit a prompt tail in O(log S)
     compiled calls (descending power-of-2 chunks) instead of up to S serial
-    B=1 decodes, while keeping the compile cache bounded: the cache shape is
-    fixed, so only C varies.
+    B=1 decodes, while keeping the compile cache bounded: the pools and the
+    table width are fixed, so only C and the number of rows B vary (the
+    paged engine passes the pow-2 cover of the rows that take a chunk).
     """
     tokens = inputs["tokens"]          # [B, C] int32
     B, C = tokens.shape

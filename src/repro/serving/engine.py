@@ -31,14 +31,16 @@ neighbour's traffic sees all of its requests at once):
      TBT cannot balloon under a thundering herd (at least one request is
      always admitted so oversized prompts cannot starve).
 
-The extend calls run at the engine's fixed batch with a row mask (masked
-rows keep their old cache bits), so the compile cache stays
-O(log max_seq) extend entries + O(log max_seq) x O(log max_batch) prefill
-entries + one decode entry. Right-padding prompts instead would corrupt
-recurrent/SSM states and shift last-token logits, so it is deliberately
-not used. ``admit_mode="serial"`` keeps the old one-request-at-a-time
-path (pow2-prefix prefill + B=1 decode tail) as the equivalence
-reference.
+On the paged cache an extend call runs only the rows that take its chunk,
+padded to the next power of two; on the dense cache it runs at the
+engine's fixed batch with a row mask (masked rows keep their old cache
+bits). The compile cache stays O(log max_seq) x O(log max_batch) paged
+extend entries (O(log max_seq) dense) + O(log max_seq) x
+O(log max_batch) prefill entries + one decode entry. Right-padding
+prompts instead would corrupt recurrent/SSM states and shift last-token
+logits, so it is deliberately not used. ``admit_mode="serial"`` keeps
+the old one-request-at-a-time path (pow2-prefix prefill + B=1 decode
+tail) as the equivalence reference.
 
 Paged KV cache (``paged=True``)
 -------------------------------
@@ -63,8 +65,8 @@ token) replaces rows with a shared pool of ``num_pages`` pages of
     page count covering the live slots, so short sequences stop paying
     attention bandwidth for the dead tail of ``max_seq`` — the compile
     cache stays O(log max_seq/page_size) decode variants (admission
-    extends run at the full table width, keyed only by chunk length,
-    exactly like the dense path);
+    extends run at the full table width over only the rows that take
+    the chunk, keyed by chunk length and power-of-two row count);
   * ``num_pages`` defaults to dense-equivalent capacity
     (``max_batch * max_seq / page_size``) but the two knobs decouple:
     the same byte budget can back far more *slots* than the dense
@@ -250,6 +252,13 @@ def _insert_leaf(engine_leaf, req_leaf, slot: int):
     # stacked leaves: [L, B, ...] — batch at axis 1, write at origin elsewhere
     start = (0, slot) + (0,) * (engine_leaf.ndim - 2)
     return jax.lax.dynamic_update_slice(engine_leaf, req_leaf, start)
+
+
+def _take_rows(leaf, rows):
+    """Rows ``rows`` of a [B]-batched cache leaf (batch at axis 0 for 1-D
+    leaves, axis 1 for stacked ones); an id past the batch reads zeros."""
+    return jnp.take(leaf, rows, axis=0 if leaf.ndim == 1 else 1,
+                    mode="fill", fill_value=0)
 
 
 @jax.jit
@@ -519,7 +528,10 @@ class ServingEngine:
         """One extend chunk over the full engine cache; rows with
         ``mask[b] == False`` keep their old cache bits (so live decode
         slots and idle slots are untouched). Compiled once per chunk
-        length — the engine batch is fixed."""
+        length — the engine batch is fixed. Serves the dense cache (MLA,
+        the recurrent families, ``paged=False``), whose per-slot rows
+        would need a whole-row gather and scatter to run only the takers;
+        the paged twin does that."""
         logits, new_cache = self.model.extend_fn(params, {"tokens": tokens},
                                                  cache)
 
@@ -530,19 +542,24 @@ class ServingEngine:
 
         return logits, jax.tree.map(sel, new_cache, cache)
 
-    def _masked_extend_paged(self, params, tokens, mask, cache):
-        """Paged twin of ``_masked_extend``: masked rows' block-table rows
-        are swapped for the sentinel INSIDE the jit, so their page writes
-        drop at the scatter (no post-hoc cache select over the shared
-        pools — a pool page belongs to exactly one slot) and their ``pos``
-        is restored. Runs at the FULL table width so the compile cache is
-        keyed only by chunk length, like the dense path."""
-        tbl = jnp.where(mask[:, None], cache["table"], self.num_pages)
+    def _masked_extend_paged(self, params, tokens, rows, cache):
+        """Paged extend over only the rows that take the chunk: ``tokens``
+        [R, C] and ``cache["table"]`` [R, maxP] are the takers' rows,
+        ``rows`` [R] their slots. The [B]-batched leaves (``pos``, enc-dec
+        ``enc_kv``) are gathered at ``rows`` inside the program, the new
+        ``pos`` is scattered back, and the shared pools go in whole.
+        Padding rows carry an all-sentinel table row, so their page writes
+        drop, and a slot id past ``max_batch``, so their ``pos`` scatter
+        drops too. The table keeps its full width, so each row's attention
+        is the full-batch call's; compiled once per (chunk, R). Returns
+        the logits [R, V] and the pools + ``pos`` (+ ``enc_kv``)."""
+        rest = {k: v for k, v in cache.items()
+                if k not in ("kv", "table", "span")}
         logits, new_cache = self.model.extend_fn(
-            params, {"tokens": tokens}, {**cache, "table": tbl})
-        new_cache["pos"] = jnp.where(mask, new_cache["pos"], cache["pos"])
-        new_cache["table"] = cache["table"]
-        return logits, new_cache
+            params, {"tokens": tokens},
+            {**cache, **jax.tree.map(lambda x: _take_rows(x, rows), rest)})
+        pos = cache["pos"].at[rows].set(new_cache["pos"], mode="drop")
+        return logits, {**rest, "kv": new_cache["kv"], "pos": pos}
 
     def _masked_decode(self, params, inputs, mask, cache):
         """Async-mode decode with pending-admission rows masked out. Dense:
@@ -890,26 +907,43 @@ class ServingEngine:
         req.finish_s = self._clock()
         self.metrics.rejected.append(req)
 
-    def _dispatch_extend(self, toks, mask, takers: list, C: int):
-        """One masked extend-chunk dispatch over the full engine batch
-        (dense: cache-select mask; paged: sentinel table rows, full table
-        width). Shared by the batched tail loop and the async arbiter."""
-        with _host_span("extend", chunk=C, rows=len(takers),
-                        batch=len(toks)):
+    def _dispatch_extend(self, chunks: list, C: int) -> tuple:
+        """One extend-chunk dispatch for ``chunks`` [(slot, C tokens)],
+        shared by the batched tail loop and the async arbiter. Paged: only
+        the takers' rows run, padded to the next power of two ``R``, at
+        the full table width (``_masked_extend_paged``). Dense: the full
+        engine batch with a cache-select mask. Returns the logits and each
+        taker's row in them, in ``chunks`` order."""
+        takers = [slot for slot, _ in chunks]
+        if self.paged:
+            R = 1 << (len(chunks) - 1).bit_length()
+            toks = np.zeros((R, C), np.int32)
+            rows = np.full((R,), self.max_batch, np.int32)
+            tbl = np.full((R, self._maxP), self.num_pages, np.int32)
+            for r, (slot, t) in enumerate(chunks):
+                toks[r], rows[r], tbl[r] = t, slot, self._tbl[slot]
+            at = list(range(len(chunks)))
+        else:
+            R = self.max_batch
+            toks = np.zeros((R, C), np.int32)
+            mask = np.zeros((R,), bool)
+            for slot, t in chunks:
+                toks[slot], mask[slot] = t, True
+            at = takers
+        with _host_span("extend", chunk=C, rows=len(chunks), batch=R):
             if self.paged:
-                logits, new_cache = self._extend_paged(
-                    self.params, jnp.asarray(toks), jnp.asarray(mask),
-                    self._call_cache(self._maxP))
-                self.cache = self._strip_table(new_cache)
-                for slot in takers:
-                    self._slot_len[slot] += C
+                logits, self.cache = self._extend_paged(
+                    self.params, jnp.asarray(toks), jnp.asarray(rows),
+                    {**self.cache, "table": jnp.asarray(tbl),
+                     "span": self._span})
+                self._slot_len[takers] += C
             else:
                 logits, self.cache = self._extend(
                     self.params, jnp.asarray(toks), jnp.asarray(mask),
                     self.cache)
         self.metrics.prefill_calls += 1
         self.metrics.extend_chunks += 1
-        return logits
+        return logits, at
 
     def _admit_batched(self, admits: list) -> None:
         """Grouped prefill + shared descending-pow2 extend tails. Operates
@@ -955,22 +989,17 @@ class ServingEngine:
             # rows; every row with that bit set advances this round
             C = max(1 << ((len(full) - cons).bit_length() - 1)
                     for req, full, cons in pend.values())
-            toks = np.zeros((self.max_batch, C), np.int32)
-            mask = np.zeros((self.max_batch,), bool)
-            takers = []
-            for slot, (req, full, cons) in pend.items():
-                if (len(full) - cons) & C:
-                    toks[slot] = full[cons:cons + C]
-                    mask[slot] = True
-                    takers.append(slot)
-            logits = self._dispatch_extend(toks, mask, takers, C)
+            chunks = [(slot, full[cons:cons + C])
+                      for slot, (req, full, cons) in pend.items()
+                      if (len(full) - cons) & C]
+            logits, at = self._dispatch_extend(chunks, C)
             fins = []
-            for slot in takers:
+            for (slot, _), row in zip(chunks, at):
                 req, full, cons = pend[slot]
                 cons += C
                 if cons == len(full):
                     del pend[slot]
-                    fins.append((slot, req, slot))
+                    fins.append((slot, req, row))
                 else:
                     pend[slot][2] = cons
             self._finalize_admits(fins, logits)
@@ -993,24 +1022,19 @@ class ServingEngine:
                           for _req, full, cons in self._pend.values())
             cap = min(max_rem, remaining)
             C = 1 << (cap.bit_length() - 1)
-            toks = np.zeros((self.max_batch, C), np.int32)
-            mask = np.zeros((self.max_batch,), bool)
-            takers = []
-            for slot, (_req, full, cons) in self._pend.items():
-                if len(full) - cons >= C:
-                    toks[slot] = full[cons:cons + C]
-                    mask[slot] = True
-                    takers.append(slot)
-            logits = self._dispatch_extend(toks, mask, takers, C)
+            chunks = [(slot, full[cons:cons + C])
+                      for slot, (_req, full, cons) in self._pend.items()
+                      if len(full) - cons >= C]
+            logits, at = self._dispatch_extend(chunks, C)
             fins = []
-            for slot in takers:
+            for (slot, _), row in zip(chunks, at):
                 entry = self._pend[slot]
                 entry[2] += C
                 if entry[2] == len(entry[1]):
                     del self._pend[slot]
-                    fins.append((slot, entry[0], slot))
+                    fins.append((slot, entry[0], row))
             self._finalize_admits(fins, logits)
-            spent += C * len(takers)
+            spent += C * len(chunks)
         return spent
 
     def _admit_async(self) -> tuple[int, int]:

@@ -44,15 +44,18 @@ DENSE_ONLY = {"rwkv6-1.6b", "zamba2-7b", "deepseek-v2-236b"}
 @pytest.fixture
 def assert_compile_bounds():
     """Compile-cache budget for the paged engine: extends are always
-    dispatched at the FULL table width, so paged-extend variants are
-    keyed only by chunk size — O(log max_seq) entries; decode runs at
-    the pow-2 page cover of the longest live row — O(log maxP)
-    variants. An unbounded cache here means per-width recompiles in
-    production serving."""
+    dispatched at the FULL table width over the pow-2 cover of the rows
+    that take the chunk, so paged-extend variants are keyed by chunk
+    size and row count — O(log max_seq) x O(log max_batch) entries;
+    decode runs at the pow-2 page cover of the longest live row —
+    O(log maxP) variants. An unbounded cache here means per-width
+    recompiles in production serving."""
     def check(eng):
         n_seq = int(math.log2(eng.max_seq)) + 1
+        n_bat = int(math.log2(eng.max_batch)) + 1
         n_pages = int(math.log2(max(eng.max_seq // eng.page_size, 1))) + 1
-        for fn, bound in ((getattr(eng, "_extend_paged", None), n_seq),
+        for fn, bound in ((getattr(eng, "_extend_paged", None),
+                           n_seq * n_bat),
                           (eng._decode, n_pages),
                           (getattr(eng, "_decode_masked", None), n_pages)):
             if fn is not None and hasattr(fn, "_cache_size"):
@@ -169,6 +172,51 @@ def test_paged_int8_cache_matches_dense_int8():
     np.testing.assert_array_equal(np.asarray(ld2), np.asarray(lp2))
     assert paged["kv"]["k"].dtype == jnp.int8
     assert "k_scale" in paged["kv"]
+
+
+@pytest.mark.parametrize("takers", [[1, 3], [0, 2, 3]])
+def test_compacted_extend_int8_pool_matches_full_batch(takers):
+    """The engine's paged extend over only the taking rows (padded to a
+    power of two with a sentinel row) is bitwise the full-batch call with
+    the other rows' tables sentineled, on an int8 page pool: the same
+    logits per taker, the same pools, and ``pos`` advanced for the takers
+    alone."""
+    cfg, model, params = _build("qwen3-14b")
+    B, S, page = 4, 64, 16
+    eng = _engine(model, params, paged=True, max_batch=B, max_seq=S,
+                  page_size=page)
+    cache = T.make_paged_decode_cache(cfg, B, S, page_size=page,
+                                      dtype="int8")
+    P, maxP = B * (S // page), S // page
+    table = np.arange(P, dtype=np.int32).reshape(B, maxP)
+    rng = np.random.default_rng(12)
+    prompt = rng.integers(0, cfg.vocab_size, size=(B, 12)).astype(np.int32)
+    _, cache = model.extend_fn(params, {"tokens": jnp.asarray(prompt)},
+                               {**cache, "table": jnp.asarray(table)})
+    chunk = rng.integers(0, cfg.vocab_size, size=(B, 4)).astype(np.int32)
+    take = np.isin(np.arange(B), takers)
+    lf, full = model.extend_fn(
+        params, {"tokens": jnp.asarray(chunk)},
+        {**cache, "table": jnp.asarray(np.where(take[:, None], table, P))})
+
+    R = 1 << (len(takers) - 1).bit_length()
+    rows = np.full((R,), B, np.int32)
+    rows[:len(takers)] = takers
+    toks = np.zeros((R, 4), np.int32)
+    toks[:len(takers)] = chunk[takers]
+    tbl = np.full((R, maxP), P, np.int32)
+    tbl[:len(takers)] = table[takers]
+    lc, comp = eng._extend_paged(params, jnp.asarray(toks),
+                                 jnp.asarray(rows),
+                                 {**cache, "table": jnp.asarray(tbl)})
+    assert lc.shape[0] == R
+    np.testing.assert_array_equal(np.asarray(lc)[:len(takers)],
+                                  np.asarray(lf)[takers])
+    assert comp["kv"]["k"].dtype == jnp.int8 and "k_scale" in comp["kv"]
+    for a, b in zip(jax.tree.leaves(comp["kv"]), jax.tree.leaves(full["kv"])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(comp["pos"]),
+                                  np.where(take, 16, 12))
 
 
 # ------------------------------------------------------- page accounting
@@ -340,6 +388,51 @@ def test_async_interleaves_and_bounds_per_step_work(assert_compile_bounds):
     assert s["decode_steps"] > 0
     util = eng.reconcile()["decode_utilization"]
     assert util["decode_steps"] == s["decode_steps"]
+    assert_compile_bounds(eng)
+
+
+def test_extend_leaves_other_pages_untouched(assert_compile_bounds):
+    """Async paged admission of three overlapping tails (15, 14, 12 =
+    bucket 8 + chunks 4+2+1, 4+2, 4) beside a live decoding request:
+    across every extend dispatch (three takers in four rows, then two,
+    then one) no page but the takers' changes, so the live slot's pages
+    and the free pages are bitwise as they were. The streams are pinned
+    against serial and dense in ``test_serving_batched``."""
+    cfg, model, params = _build("qwen3-14b")
+    rng = np.random.default_rng(11)
+    first, *wave = [Request(rid=i, prompt=rng.integers(
+        0, cfg.vocab_size, size=n).astype(np.int32), max_new_tokens=new)
+        for i, (n, new) in enumerate(((8, 12), (15, 4), (14, 4), (12, 4)))]
+    eng = _engine(model, params, "async", paged=True)
+    seen = []
+    inner = eng._dispatch_extend
+
+    def spy(chunks, C):
+        takers = [slot for slot, _ in chunks]
+        mine = [p for s in takers for p in eng.slot_pages[s]]
+        others = [p for p in range(eng.num_pages) if p not in mine]
+        live = [p for s, r in enumerate(eng.active) if r is not None
+                for p in eng.slot_pages[s]]
+        before = jax.tree.map(np.asarray, eng.cache["kv"])
+        logits, at = inner(chunks, C)
+        after = jax.tree.map(np.asarray, eng.cache["kv"])
+        seen.append((len(takers), logits.shape[0]))
+        assert live and eng._free_pages
+        assert set(live) | set(eng._free_pages) <= set(others)
+        for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+            np.testing.assert_array_equal(a[:, others], b[:, others])
+        assert any((a[:, mine] != b[:, mine]).any() for a, b in
+                   zip(jax.tree.leaves(before), jax.tree.leaves(after)))
+        return logits, at
+
+    eng._dispatch_extend = spy
+    eng.submit(first)
+    eng.step()
+    for r in wave:
+        eng.submit(r)
+    assert eng.run().summary()["num_completed"] == 4
+    assert seen == [(3, 4), (2, 2), (1, 1)]
+    assert eng.reconcile()["balanced"]
     assert_compile_bounds(eng)
 
 

@@ -129,6 +129,52 @@ def test_burst_admission_dispatch_and_compile_bounds():
     assert calls["serial"] >= 4 * calls["batched"], calls
 
 
+def _overlapping_tails(cfg):
+    """A request that goes live first, then a wave of three whose tails
+    overlap: 15 = 8 + 4+2+1, 14 = 8 + 4+2, 12 = 8 + 4, so the tail chunks
+    4, 2 and 1 have three, two and one takers."""
+    rng = np.random.default_rng(11)
+    return [Request(rid=i, prompt=rng.integers(0, cfg.vocab_size, size=n)
+                    .astype(np.int32), max_new_tokens=new, temperature=t)
+            for i, (n, new, t) in enumerate(((8, 12, 0.7), (15, 4, 0.0),
+                                             (14, 4, 1.1), (12, 4, 0.0)))]
+
+
+def _serve_overlapping(eng, cfg):
+    first, *wave = _overlapping_tails(cfg)
+    eng.submit(first)
+    eng.step()
+    for r in wave:
+        eng.submit(r)
+    m = eng.run()
+    assert m.summary()["num_completed"] == 4
+    return {r.rid: list(r.tokens) for r in m.completed}
+
+
+@pytest.mark.parametrize("mode", ["batched", "async"])
+def test_overlapping_tails_extend_only_their_rows(mode):
+    """The paged extend runs the pow-2 cover of the rows that take each
+    chunk (three takers -> 4 rows, one of them padding; then 2; then 1),
+    each taker's logits come back at its compacted row, and the streams
+    stay bitwise those of the serial reference and the dense cache."""
+    cfg, model, params = _build("qwen3-14b")
+    eng = _engine(model, params, mode, paged=True)
+    seen = []
+    inner = eng._dispatch_extend
+
+    def spy(chunks, C):
+        logits, at = inner(chunks, C)
+        seen.append((len(chunks), logits.shape[0], list(at)))
+        return logits, at
+
+    eng._dispatch_extend = spy
+    got = _serve_overlapping(eng, cfg)
+    assert seen == [(3, 4, [0, 1, 2]), (2, 2, [0, 1]), (1, 1, [0])]
+    assert got == _serve_overlapping(_engine(model, params, "serial"), cfg)
+    assert got == _serve_overlapping(_engine(model, params, mode), cfg)
+    assert eng.reconcile()["balanced"]
+
+
 def test_admit_token_budget_bounds_per_step_work():
     """The budget caps prompt tokens admitted per step (FIFO, >= 1 request
     per step so oversized prompts cannot starve), trading admission
