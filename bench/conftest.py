@@ -31,18 +31,21 @@ TINY_MIX = {"name": "tiny", "prompt": {"median": 40, "sigma": 0.6, "min": 4,
 TINY_LIMIT = 0.05
 
 
-def write_tiny_root(root: str) -> str:
+def write_tiny_root(root: str, config: dict = TINY_CONFIG) -> str:
     """A checkout with ``BENCHMARK.json`` naming one cell, ``tiny.tiny``,
-    whose configuration, mix, limits and metric readers are files of its
-    own; the harness code itself is the repository's."""
+    whose configuration (``config``), mix, limits, metric readers and
+    references are files of its own; the harness code itself is the
+    repository's."""
     b = os.path.join(root, "bench")
     for d in ("configs", "traffic", "cells"):
         os.makedirs(os.path.join(b, d), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"), os.path.join(b, "metrics"),
-                    dirs_exist_ok=True)
+    for d in ("metrics", "reference"):
+        shutil.copytree(os.path.join(BENCH, d), os.path.join(b, d),
+                        dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(os.path.join(BENCH, "peaks.json"), b)
     dump = lambda obj, *p: json.dump(obj, open(os.path.join(b, *p), "w"))
-    dump(TINY_CONFIG, "configs", "tiny.json")
+    dump(config, "configs", "tiny.json")
     dump(TINY_MIX, "traffic", "tiny.json")
     dump({"max_logit_gap": {"limit": TINY_LIMIT}}, "cells", "tiny.tiny.json")
     spec = {

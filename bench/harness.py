@@ -3,9 +3,11 @@ drain, and the correctness check against the plain reference.
 
 Everything about a cell is found by name: the cell in ``BENCHMARK.json``,
 its configuration in ``bench/configs/<config>.json``, its traffic in
-``bench/traffic/<mix>.json``, its limits in ``bench/cells/<cell>.json`` and
-each metric's reader in ``bench/metrics/<metric>.py``. Adding a cell, a
-configuration, a mix or a metric adds files and entries; this file stays.
+``bench/traffic/<mix>.json``, its limits in ``bench/cells/<cell>.json``,
+each metric's reader in ``bench/metrics/<metric>.py`` and the plain
+reference the configuration names in ``bench/reference/<module>.py``.
+Adding a cell, a configuration, a mix, a metric or a reference adds files
+and entries; this file stays.
 
 The timed path is the program's serving engine: ``ServingEngine.step`` with
 the paged cache and async admission, over the model built from the
@@ -24,6 +26,7 @@ import shutil
 import sys
 import time
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Callable, Optional
 
 import numpy as np
@@ -55,6 +58,7 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list
+    reference: ModuleType
 
 
 def _read_json(path: str) -> dict:
@@ -87,17 +91,53 @@ def load_cell(root: str, workload: str) -> Cell:
                 end_to_end=[m for m in spec["end_to_end"]
                             if _applies(m, workload)],
                 per_layer=[m for m in spec["per_layer"]
-                           if _applies(m, workload)])
+                           if _applies(m, workload)],
+                reference=load_reference(root, config))
+
+
+def _load_module(name: str, path: str) -> ModuleType:
+    """The Python file at ``path``, executed as a module registered under
+    ``name`` (a dataclass in it looks its module up there)."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(root: str, metric: str) -> Callable:
     """``read(run) -> float | None`` from ``bench/metrics/<metric>.py``."""
     path = os.path.join(root, "bench", "metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(
+        "bench_metric_" + metric.replace(".", "_").replace("-", "_"),
+        path).read
+
+
+REFERENCE_NAMES = ("Dims", "make_weights", "logits_at")
+
+
+def reference_name(config: dict) -> str:
+    """The configuration's ``reference.module``; ``gqa`` where it names
+    none."""
+    return config.get("reference", {}).get("module", "gqa")
+
+
+def load_reference(root: str, config: dict) -> ModuleType:
+    """The plain reference the configuration names, from
+    ``bench/reference/<module>.py``, checked for the names of the contract
+    in ``bench/reference/__init__.py``."""
+    name = reference_name(config)
+    path = os.path.join(root, "bench", "reference", f"{name}.py")
+    if not name.isidentifier() or not os.path.exists(path):
+        raise Refused(f"reference.module {name!r}: no bench/reference/"
+                      f"{name}.py in {root}")
+    mod = _load_module("bench_reference_" + name, path)
+    missing = [n for n in REFERENCE_NAMES if not hasattr(mod, n)]
+    if not missing and not callable(getattr(mod.Dims, "from_config", None)):
+        missing = ["Dims.from_config"]
+    if missing:
+        raise Refused(f"reference.module {name!r} lacks {missing}")
+    return mod
 
 
 # ----------------------------------------------------------------- devices
@@ -177,29 +217,65 @@ def enable_compile_cache(root: str) -> Optional[str]:
 
 
 # ----------------------------------------------------------------- program
+REQUIRED_FIELDS = ("d_model", "num_layers", "num_heads", "vocab_size",
+                   "tie_embeddings", "dtype")
+
+# The published keys of a dense GQA decoder's Hugging Face config.json, for
+# a file that names no ``program.published`` map of its own.
+GQA_PUBLISHED = {"d_model": "hidden_size", "num_layers": "num_hidden_layers",
+                 "num_heads": "num_attention_heads",
+                 "num_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
+                 "vocab_size": "vocab_size", "d_ff": "intermediate_size",
+                 "tie_embeddings": "tie_word_embeddings",
+                 "dtype": "torch_dtype"}
+
+# Published widths and counts: where the file holds one, the map has to
+# compare some field with it, so that a map cannot leave a width unchecked.
+PUBLISHED_WIDTHS = (
+    "hidden_size", "num_hidden_layers", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "intermediate_size",
+    "moe_intermediate_size", "vocab_size", "num_local_experts",
+    "n_routed_experts", "n_shared_experts", "num_experts_per_tok",
+    "kv_lora_rank", "q_lora_rank", "qk_rope_head_dim", "qk_nope_head_dim",
+    "v_head_dim", "mamba_d_state", "mamba_headdim", "mamba_expand",
+    "mamba_d_conv")
+
+
+def _published(config: dict, cfg) -> dict:
+    """``{ModelConfig field: value the file states}`` through the file's
+    ``program.published`` map of ``{field: published key}``, or
+    ``GQA_PUBLISHED`` where it has none. The map must name every field of
+    ``REQUIRED_FIELDS`` and use every key of ``PUBLISHED_WIDTHS`` that the
+    file holds; a field ``ModelConfig`` lacks, or a published key the file
+    lacks, is an error that names it."""
+    pub = config["program"].get("published", GQA_PUBLISHED)
+    fields = {f.name for f in dataclasses.fields(cfg)}
+    errors = [f"program.published lacks required field {k!r}"
+              for k in REQUIRED_FIELDS if k not in pub]
+    errors += [f"program.published compares no field with the published "
+               f"width {k!r} that the file holds"
+               for k in PUBLISHED_WIDTHS if k in config
+               and k not in pub.values()]
+    errors += [f"program.published names {k!r}, not a ModelConfig field"
+               for k in pub if k not in fields]
+    errors += [f"program.published maps {k!r} to {v!r}, not a key of the "
+               f"file" for k, v in pub.items() if v not in config]
+    if errors:
+        raise ValueError("; ".join(errors))
+    return {k: config[v] for k, v in pub.items()}
+
+
 def program_config(config: dict):
     """The registry entry the configuration names, cut by its overrides,
-    checked against the widths the configuration file states."""
+    checked against the widths the configuration file states: every pair
+    of its published map."""
     from repro.configs import get_config
 
     prog = config["program"]
     cfg = dataclasses.replace(get_config(prog["registry"]),
                               **prog["overrides"])
-    want = {"d_model": config["hidden_size"],
-            "num_layers": config["num_hidden_layers"],
-            "num_heads": config["num_attention_heads"],
-            "num_kv_heads": config["num_key_value_heads"],
-            "head_dim": config["head_dim"],
-            "vocab_size": config["vocab_size"],
-            "num_experts": config.get("num_local_experts", 0),
-            "experts_per_token": config.get("num_experts_per_tok", 0),
-            "tie_embeddings": config["tie_word_embeddings"],
-            "dtype": config["torch_dtype"]}
-    ff = "moe_d_ff" if want["num_experts"] else "d_ff"
-    want[ff] = config["intermediate_size"]
+    want = _published(config, cfg)
     got = {k: getattr(cfg, k) for k in want}
-    if ff == "moe_d_ff":
-        got[ff] = cfg.resolved_moe_d_ff
     if got != want:
         bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
         raise ValueError(f"program config departs from the file: {bad}")
@@ -443,19 +519,16 @@ def sample_for_check(recs: list[Rec], seed: int, min_tokens: int,
     return pick
 
 
-def logit_gaps(config: dict, seed: int, picks: list[Rec],
+def logit_gaps(ref: ModuleType, config: dict, seed: int, picks: list[Rec],
                control: Optional[str] = None) -> dict:
-    """Widest gap by which a served token's reference logit lies below the
-    reference's best, over every served token of ``picks``. With
-    ``control`` (``"fp8"``) also ``control_max_logit_gap``: the same gap
-    for the token that the reference computed in that precision puts first
-    at each of those positions, its tokens in the served tokens' place."""
-    import jax
-
-    from bench.reference import gqa
-
-    m = gqa.Dims.from_config(config)
-    w = gqa.make_weights(m, jax_key(seed))
+    """Widest gap by which a served token's logit from the reference module
+    ``ref`` lies below the reference's best, over every served token of
+    ``picks``. With ``control`` (``"fp8"``) also ``control_max_logit_gap``:
+    the same gap for the token that the reference computed in that
+    precision puts first at each of those positions, its tokens in the
+    served tokens' place."""
+    m = ref.Dims.from_config(config)
+    w = ref.make_weights(m, jax_key(seed))
     out = {"max_logit_gap": 0.0, "tokens_checked": 0}
     if control is not None:
         out["control_max_logit_gap"] = 0.0
@@ -464,14 +537,14 @@ def logit_gaps(config: dict, seed: int, picks: list[Rec],
         served = np.asarray(r.req.tokens, np.int64)
         seq = np.concatenate([prompt, served[:-1]]).astype(np.int32)
         at = np.arange(len(prompt) - 1, len(seq))
-        ref = gqa.logits_at(m, w, seq, at)
-        best = ref.max(axis=-1)
-        gap = best - ref[np.arange(len(at)), served]
+        lg = ref.logits_at(m, w, seq, at)
+        best = lg.max(axis=-1)
+        gap = best - lg[np.arange(len(at)), served]
         out["max_logit_gap"] = max(out["max_logit_gap"], float(gap.max()))
         out["tokens_checked"] += len(at)
         if control is not None:
-            pick = gqa.logits_at(m, w, seq, at, quant=control).argmax(-1)
-            cg = float((best - ref[np.arange(len(at)), pick]).max())
+            pick = ref.logits_at(m, w, seq, at, quant=control).argmax(-1)
+            cg = float((best - lg[np.arange(len(at)), pick]).max())
             out["control_max_logit_gap"] = max(out["control_max_logit_gap"],
                                                cg)
     del w
@@ -586,7 +659,9 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     comp = CompileLog.get()
     arrivals = traffic.schedule(cell.mix, seconds, seed,
                                 cell.config["vocab_size"])
-    log = {"weights_engine_s": time.perf_counter() - t_start}
+    log = {"weights_engine_s": time.perf_counter() - t_start,
+           "reference_module": reference_name(cell.config),
+           "engine_paged": bool(engine.paged)}
     log.update(warm(engine, cell, arrivals, seed))
 
     clock = time.perf_counter
@@ -683,7 +758,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     gaps = {"max_logit_gap": math.inf, "tokens_checked": 0}
     t_ref = time.perf_counter()
     if check and picks:
-        gaps = logit_gaps(cell.config, seed, picks, control=control)
+        gaps = logit_gaps(cell.reference, cell.config, seed, picks,
+                          control=control)
     log["reference_s"] = time.perf_counter() - t_ref
     log["checked_requests"] = len(picks)
     log["tokens_checked"] = gaps["tokens_checked"]

@@ -21,6 +21,7 @@ def test_sound_run_passes(tiny_root):
     assert out["checks"]["max_logit_gap"]["value"] <= TINY_LIMIT
     assert log["tokens_checked"] >= 20
     assert log["window_compiles"] == 0
+    assert log["reference_module"] == "gqa" and log["engine_paged"] is True
     assert set(out["metrics"]) == {"setup_s", "ttft_mean_ms", "tbt_mean_ms"}
 
 
